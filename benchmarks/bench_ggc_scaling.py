@@ -58,11 +58,11 @@ def run(bench: Bench):
                 take = min(budget, len(others))
                 cand[k, rng.choice(others, take, replace=False)] = True
             candj = jnp.asarray(cand)
+            graph = eng.jit(lambda f, c, b=budget: all_clients_graph(
+                jax.random.PRNGKey(1), f, eng.p, c, reward, b))
 
             def build():
-                adj = all_clients_graph(jax.random.PRNGKey(1), flat, eng.p,
-                                        candj, reward, budget)
-                return jax.block_until_ready(adj)
+                return jax.block_until_ready(graph(flat, candj))
 
             build()  # compile
             t0 = time.time()
@@ -168,7 +168,7 @@ def _mesh_worker(n_clients, budget, devices, repeats=3):
     flat = eng.flatten(eng.init_clients(jax.random.PRNGKey(0)))
     reward = eng.make_reward_fn()
     cand = jnp.ones((n_clients, n_clients), bool)
-    jf = jax.jit(lambda k, f: all_clients_graph(
+    jf = eng.jit(lambda k, f: all_clients_graph(
         k, f, eng.p, cand, reward, budget, mesh=mesh,
         client_axes=eng.client_axes))
     key = jax.random.PRNGKey(1)

@@ -174,8 +174,9 @@ def bench_dpfl_mesh(rounds=10, n_clients=16, device_counts=(1, 2, 4, 8),
                     graph_repr="dense"):
     """rounds/sec of the mesh-sharded round engine vs device count. Each
     count runs in a subprocess because --xla_force_host_platform_device_count
-    must be set before jax imports."""
+    must be set before jax imports. Returns the number of failed counts."""
     print("pair,tag,status,loop_s,rounds_per_s,,,,")
+    failed = 0
     for d in device_counts:
         if n_clients % d:
             print(f"dpfl_mesh,devices={d},skip(n_clients%d),,,,,,")
@@ -195,8 +196,10 @@ def bench_dpfl_mesh(rounds=10, n_clients=16, device_counts=(1, 2, 4, 8),
         if r.returncode or not out:
             print(f"dpfl_mesh,devices={d},failed,,,,,,")
             sys.stderr.write(r.stdout[-2000:] + r.stderr[-2000:])
+            failed += 1
             continue
         print(out[-1])
+    return failed
 
 
 def main():
@@ -235,9 +238,10 @@ def main():
             args.rounds, args.clients = 8, 12
         if args.mesh:
             counts = tuple(int(d) for d in args.device_counts.split(","))
-            bench_dpfl_mesh(rounds=args.rounds, n_clients=args.clients,
-                            device_counts=counts,
-                            graph_repr=args.graph_repr)
+            if bench_dpfl_mesh(rounds=args.rounds, n_clients=args.clients,
+                               device_counts=counts,
+                               graph_repr=args.graph_repr):
+                sys.exit(1)
         else:
             bench_dpfl_rounds(rounds=args.rounds, n_clients=args.clients,
                               out=args.out, graph_repr=args.graph_repr)

@@ -15,6 +15,7 @@ from repro.core import DPFLConfig, graph_stats, run_dpfl, run_dpfl_reference
 from repro.data import make_federated_classification
 from repro.fl.baselines import BASELINES, run_baseline
 from repro.fl.engine import FLEngine
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.classifier import MLP, PaperCNN
 from repro.configs.paper_cnn import CONFIG as CNN_CONFIG
 
@@ -39,6 +40,7 @@ def main():
                     help="compiled = device-resident round engine; "
                          "host = original python round loop (reference)")
     args = ap.parse_args()
+    use_compile_cache()
 
     img = args.model == "cnn"
     data = make_federated_classification(

@@ -174,7 +174,10 @@ def donation_report(fn, *args) -> Dict[str, Any]:
     otherwise it is *blocked*. Returns ``{"donatable": [...], "blocked":
     [...], "donatable_bytes": int}``.
     """
-    out = jax.eval_shape(fn, *args)
+    # a jitted program's own eval_shape (an `FLEngine.jit` step passes
+    # its client data there; under `jax.eval_shape` it would refuse)
+    out = (fn.eval_shape(*args) if hasattr(fn, "eval_shape")
+           else jax.eval_shape(fn, *args))
     in_leaves = _leaf_paths(args[0])
     out_leaves = _leaf_paths(out)
     report = {"donatable": [], "blocked": [], "donatable_bytes": 0}

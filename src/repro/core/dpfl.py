@@ -258,7 +258,37 @@ def _cached_bggc(engine: FLEngine, cfg: DPFLConfig, reward_fn, budget: int):
                                         budget, mix_impl=cfg.mix_impl,
                                         mesh=mesh, client_axes=ca)
 
-        cache[key] = jax.jit(build)
+        cache[key] = engine.jit(build)
+    return cache[key]
+
+
+def _cached_refresh(engine: FLEngine, cfg: DPFLConfig, reward_fn,
+                    budget: int):
+    """Fetch-or-build the reference loop's jitted GGC refresh,
+    ``refresh(key, flat, p, cand, active) -> new graph`` (``cand`` is the
+    dense candidate masks or the Omega lists), memoized on the engine
+    like `_cached_bggc`. The reward reads the client data, so the refresh
+    runs as an `FLEngine.jit` program."""
+    cache = getattr(engine, "_refresh_cache", None)
+    if cache is None:
+        cache = engine._refresh_cache = {}
+    sparse = _sparse(cfg)
+    key = (budget, cfg.graph_impl, cfg.mix_impl, sparse)
+    if key not in cache:
+        if sparse:
+            def refresh(k_graph, flat, p, cand, active):
+                return all_clients_graph_sparse(
+                    k_graph, flat, p, cand, reward_fn, budget,
+                    mix_impl=cfg.mix_impl, active=active)
+        else:
+            def refresh(k_graph, flat, p, cand, active):
+                if active is not None:
+                    cand = cand & active[None, :]
+                return all_clients_graph(
+                    k_graph, flat, p, cand, reward_fn, budget,
+                    impl=cfg.graph_impl, mix_impl=cfg.mix_impl)
+
+        cache[key] = engine.jit(refresh)
     return cache[key]
 
 
@@ -817,18 +847,10 @@ def run_dpfl_reference(engine: FLEngine, cfg: DPFLConfig) -> DPFLResult:
                 int(_realized_downloads(count_graph, active)))
         if cfg.random_graph:
             adj = omega
-        elif refresh and sparse:
-            refreshed = all_clients_graph_sparse(
-                jax.random.fold_in(k_graph, 1000 + t), probe_w, p, omega,
-                reward_fn, budget, mix_impl=cfg.mix_impl, active=active)
-            adj = refreshed if active is None else \
-                jnp.where(active[:, None], refreshed, adj)
         elif refresh:
-            cand = omega if active is None else omega & active[None, :]
-            refreshed = all_clients_graph(
-                jax.random.fold_in(k_graph, 1000 + t), probe_w, p, cand,
-                reward_fn, budget, impl=cfg.graph_impl,
-                mix_impl=cfg.mix_impl)
+            refreshed = _cached_refresh(engine, cfg, reward_fn, budget)(
+                jax.random.fold_in(k_graph, 1000 + t), probe_w, p, omega,
+                active)
             adj = refreshed if active is None else \
                 jnp.where(active[:, None], refreshed, adj)
         recv = dec if comp is not None else wire

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 
 from ..analysis.registry import exchange_site
 from ..kernels import ops as _kops
-from ..sharding.compat import optimization_barrier as _barrier
 
 
 # ------------------------------------------------------------------ mixing
@@ -153,13 +152,14 @@ def greedy_decision_step(reward_fn: Callable):
         # not additionally depend on what surrounds the kernel (compiled
         # round vs host loop vs shard_map block) — fp noise here feeds the
         # a/(a+b) coin flips, which near-zero gains amplify (DESIGN.md §8)
-        probes = _barrier(jnp.stack([
+        probes = jax.lax.optimization_barrier(jnp.stack([
             wX / pX,
             (wX + p_j * w_j) / (pX + p_j),
             wY / pY,
             (wY - p_j * w_j) / jnp.maximum(pY - p_j, 1e-12),
         ]))
-        r = _barrier(jax.vmap(lambda fw: reward_fn(fw, k_idx))(probes))
+        r = jax.lax.optimization_barrier(
+            jax.vmap(lambda fw: reward_fn(fw, k_idx))(probes))
         a = jnp.maximum(r[1] - r[0], 0.0)
         b = jnp.maximum(r[3] - r[2], 0.0)
         prob = jnp.where(a + b > 0, a / (a + b), 1.0)
@@ -348,8 +348,6 @@ def _shard_clients_graph(per_client, mesh, client_axes, keys, ks,
     call (e.g. the (N,) availability mask of a participation round)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..sharding.compat import shard_map
-
     ca = tuple(client_axes)
 
     def block(keys_blk, k_blk, cand_blk, w_blk, p_full, *extra_full):
@@ -357,7 +355,7 @@ def _shard_clients_graph(per_client, mesh, client_axes, keys, ks,
         # gather cannot fuse into the reward matmuls (keeps the per-shard
         # probe numerics as close to the single-device build as XLA
         # allows — see DESIGN.md §8 on greedy-decision fp sensitivity)
-        w_full = _barrier(
+        w_full = jax.lax.optimization_barrier(
             jax.lax.all_gather(w_blk, ca, axis=0, tiled=True))
         return jax.vmap(
             per_client,
@@ -366,7 +364,7 @@ def _shard_clients_graph(per_client, mesh, client_axes, keys, ks,
 
     # check_vma=False: the probes may dispatch to the Pallas graph_mix
     # kernel, which has no shard_map replication rule
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(ca, None), P(ca), P(ca, None), P(ca, None), P(None))
         + (P(None),) * len(extra),

@@ -233,20 +233,20 @@ def make_post_train(cfg: AdversaryConfig):
 def make_adv_local_train(engine, cfg: AdversaryConfig):
     """``label_flip`` local-train: malicious clients' train labels go
     through the seeded derangement for rounds where they attack. The
-    flipped label table is a closure constant (static per cache key);
-    the per-round select is a ``jnp.where`` on ``sched[t]``, so an
-    all-False row trains on exactly the clean labels. None for the
-    model-poisoning attacks (which ride `make_post_train`)."""
+    (n_classes,) derangement is a closure constant (static per cache
+    key) applied to the engine's train labels at trace time; the
+    per-round select is a ``jnp.where`` on ``sched[t]``, so an all-False
+    row trains on exactly the clean labels. None for the model-poisoning
+    attacks (which ride `make_post_train`)."""
     if cfg.attack != "label_flip":
         return None
-    train_x, train_y = engine.train_data
-    perm = jnp.asarray(label_permutation(cfg, engine.data.n_classes))
-    flip_y = perm[train_y]
+    perm = label_permutation(cfg, engine.data.n_classes)
     base = engine.train_fn_with_labels
 
     def local_train(stacked, key, epochs, *, aux, t):
+        train_y = engine.client_data()["train"][1]
         row = aux["adv"]["sched"][t]
-        ys = jnp.where(row[:, None], flip_y, train_y)
+        ys = jnp.where(row[:, None], jnp.asarray(perm)[train_y], train_y)
         return base(stacked, key, epochs, ys)
 
     return local_train

@@ -254,20 +254,16 @@ def _prox_engine(engine, lam):
                 epoch, (params, opt_state), jax.random.split(key, epochs))
             return params, jnp.float32(0)
 
-        @functools.partial(jax.jit, static_argnames=("epochs",))
-        def _lt(stacked, key, epochs, ref):
-            # same client-axis constraints as FLEngine.train_fn — without
-            # them a client mesh could silently reshard params/data/keys
-            # mid-round when this runs inside the compiled round_step
-            N = engine.data.n_clients
-            keys = jax.random.split(key, N)
-            stacked = jax.tree.map(engine.constrain_clients, stacked)
-            return jax.vmap(
-                lambda pr, x, y, k, r: one_client(pr, x, y, k, epochs, r)
-            )(stacked, engine.constrain_clients(engine.train_data[0]),
-              engine.constrain_clients(engine.train_data[1]),
-              engine.constrain_clients(keys),
-              engine.constrain_clients(ref))
+        def _lt_fn(stacked, key, epochs, ref):
+            # client-local like FLEngine.train_fn: on a client mesh each
+            # device trains only its own clients (map_clients)
+            keys = jax.random.split(key, engine.data.n_clients)
+            train_x, train_y = engine.client_data()["train"]
+            return engine.map_clients(
+                lambda pr, x, y, k, r: one_client(pr, x, y, k, epochs, r),
+                stacked, train_x, train_y, keys, ref)
+
+        _lt = engine.jit(_lt_fn, static_argnames=("epochs",))
 
         def local_train(stacked, key, epochs, ref_flat=None):
             ref = engine.flatten(stacked) if ref_flat is None else ref_flat

@@ -169,14 +169,13 @@ def compress_exchange(cfg, flat, ef, key, *, mesh=None, client_axes=None):
         # from the full-(N, P) key stream to match the reference.
         from jax.sharding import PartitionSpec as P
 
-        from ..sharding.compat import shard_map
         ca = tuple(client_axes)
 
         def enc_dec(x_blk):
             p = encode(cfg, x_blk, None)
             return p, decode(cfg, p, x_blk.shape[1])
 
-        payload, dec = shard_map(
+        payload, dec = jax.shard_map(
             enc_dec, mesh=mesh, in_specs=P(ca, None),
             out_specs=({"vals": P(ca, None), "idx": P(ca, None)},
                        P(ca, None)))(xin)
@@ -205,8 +204,6 @@ def _mix_int8_offdiag(A_off, payload, dec, *, impl, mesh, client_axes):
         return _kops.graph_mix(A_off, dec, impl=impl)
     from jax.sharding import PartitionSpec as P
 
-    from ..sharding.compat import shard_map
-
     ca = tuple(client_axes)
 
     def row_block(a_blk, q_blk, s_blk):
@@ -217,9 +214,9 @@ def _mix_int8_offdiag(A_off, payload, dec, *, impl, mesh, client_axes):
 
     # check_vma=False: graph_mix may dispatch to the Pallas kernel, which
     # has no shard_map replication rule
-    return shard_map(row_block, mesh=mesh,
-                     in_specs=(P(ca, None), P(ca, None), P(ca)),
-                     out_specs=P(ca, None), check_vma=False)(
+    return jax.shard_map(row_block, mesh=mesh,
+                         in_specs=(P(ca, None), P(ca, None), P(ca)),
+                         out_specs=P(ca, None), check_vma=False)(
                          A_off, payload["q"], payload["scale"])
 
 

@@ -10,7 +10,8 @@ shard-local and only the graph ops communicate (DESIGN.md §8).
 """
 from __future__ import annotations
 
-import functools
+import contextlib
+import inspect
 from typing import Callable, Optional
 
 import jax
@@ -53,10 +54,9 @@ class FLEngine:
     # ----------------------------------------------------------- sharding
     def shard_clients(self, mesh, client_axes=None):
         """Commit the client axis to ``client_axes`` of ``mesh`` (default:
-        whichever of ('pod', 'data') the mesh has). Rebuilds the traced
-        fns with `with_sharding_constraint` on the client-stacked data and
-        params — closure constants do NOT inherit a `device_put` sharding
-        through jit, so the constraint must live inside the trace. N must
+        whichever of ('pod', 'data') the mesh has). Re-places the client
+        data on the mesh and rebuilds the traced fns, which then train and
+        evaluate inside a client `shard_map` (`map_clients`). N must
         divide the product of the client axis sizes."""
         if client_axes is None:
             client_axes = tuple(a for a in ("pod", "data")
@@ -79,14 +79,27 @@ class FLEngine:
         ca = self.client_axes if self.client_axes else ("pod", "data")
         return P(ca, *((None,) * (ndim - 1)))
 
-    def constrain_clients(self, arr):
-        """with_sharding_constraint on the leading client axis (identity
-        when the engine has no mesh). Trace-level, so it applies equally
-        to closure constants and intermediates."""
+    def map_clients(self, fn, *args):
+        """``jax.vmap(fn)(*args)`` over the leading client axis of every
+        leaf of ``args``. On a mesh the vmap runs inside a `jax.shard_map`
+        over the client axes, so each device trains and evaluates only
+        its own clients: left to the SPMD partitioner, a vmapped
+        convolution becomes a grouped one that is partitioned by
+        all-gathering every client's activations."""
+        vf = jax.vmap(fn)
         if self.mesh is None:
-            return arr
-        return jax.lax.with_sharding_constraint(
-            arr, NamedSharding(self.mesh, self.client_spec(arr.ndim)))
+            return vf(*args)
+
+        def specs(tree):
+            return jax.tree.map(lambda a: self.client_spec(a.ndim), tree)
+
+        # check_vma=False: the body has no collectives, and its scans
+        # start from per-client constants (optimizer state) that the
+        # varying-axes check would have to pcast one by one
+        return jax.shard_map(
+            vf, mesh=self.mesh, in_specs=specs(args),
+            out_specs=specs(jax.eval_shape(vf, *args)),
+            check_vma=False)(*args)
 
     # ------------------------------------------------------------ plumbing
     def init_clients(self, key):
@@ -108,15 +121,53 @@ class FLEngine:
         of `flatten` (ravel_pytree round trip, dtypes restored)."""
         return jax.vmap(self._unravel)(flat)
 
-    def _device_data(self, arr):
+    def _device_data(self, arr, replicated: bool = False):
         """Upload a client-stacked data array ONCE: device-resident, and
-        placed on the client mesh axes when the engine is sharded (so
-        passing it as a jit argument neither re-uploads nor reshards)."""
+        placed on the client mesh when the engine is sharded — split over
+        the client axes, or whole on every device with ``replicated``."""
         a = jnp.asarray(arr)
         if self.mesh is None:
             return a
-        return jax.device_put(
-            a, NamedSharding(self.mesh, self.client_spec(a.ndim)))
+        spec = P() if replicated else self.client_spec(a.ndim)
+        return jax.device_put(a, NamedSharding(self.mesh, spec))
+
+    def client_data(self):
+        """The client-stacked arrays the traceable fns read, as
+        ``{"train": (x, y), "val": (x, y)}``. Inside a function compiled
+        by `jit` these are that function's arguments; elsewhere they are
+        the device-resident arrays. Traceable code reads them here at
+        trace time and never closes over them, so the dataset is an input
+        of every compiled program and not a constant folded into it.
+
+        Raises when read under a trace that `jit` did not start (a plain
+        ``jax.jit``, ``vmap`` or ``scan`` over an engine fn): there the
+        arrays would be folded into the traced program as constants."""
+        if self._bound is not None:
+            return self._bound
+        if not jax.core.trace_ctx.is_top_level():
+            raise RuntimeError(
+                "FLEngine.client_data() read under a trace that "
+                "FLEngine.jit did not start: the dataset would be compiled "
+                "into the program as constants. Trace the function with "
+                "engine.jit instead of jax.jit.")
+        return self._data
+
+    @contextlib.contextmanager
+    def _bind(self, data):
+        prev, self._bound = self._bound, data
+        try:
+            yield
+        finally:
+            self._bound = prev
+
+    def jit(self, fn, *, donate_argnums=(), in_shardings=None,
+            out_shardings=None, static_argnames=()):
+        """`jax.jit` of ``fn`` that takes the client data (`client_data`)
+        as an extra, never-donated argument: see `DataJit`."""
+        return DataJit(self, fn, donate_argnums=donate_argnums,
+                       in_shardings=in_shardings,
+                       out_shardings=out_shardings,
+                       static_argnames=static_argnames)
 
     def _build(self):
         """Builds the raw traceable fns (`train_fn`, `eval_split_fn`,
@@ -124,7 +175,10 @@ class FLEngine:
         §5) and their standalone jitted wrappers (`local_train`,
         `_eval_split`), plus the device-resident (mesh-placed) train/val/
         test arrays — hoisted here so no per-call ``jnp.asarray`` ever
-        re-uploads them at dispatch time."""
+        re-uploads them at dispatch time. Validation is whole on every
+        device of a mesh: the GGC reward probes read it from inside the
+        client `shard_map`, where a client-split array would be
+        all-gathered on every refresh."""
         model, opt = self.model, self.opt
         bs = self.batch_size
         loss_fn = self.loss_fn
@@ -161,54 +215,47 @@ class FLEngine:
 
         self.train_data = (self._device_data(self.data.train_x),
                            self._device_data(self.data.train_y))
-        self.val_data = (self._device_data(self.data.val_x),
-                         self._device_data(self.data.val_y))
+        self.val_data = (self._device_data(self.data.val_x, True),
+                         self._device_data(self.data.val_y, True))
         self.test_data = (self._device_data(self.data.test_x),
                           self._device_data(self.data.test_y))
-        train_x, train_y = self.train_data
+        self._data = {"train": self.train_data, "val": self.val_data}
+        self._bound = None
 
         def train_fn_with_labels(stacked, key, epochs, ys):
-            N = self.data.n_clients
-            keys = jax.random.split(key, N)
-            stacked = jax.tree.map(self.constrain_clients, stacked)
-            return jax.vmap(
-                lambda p, x, y, k: one_client_epochs(p, x, y, k, epochs)
-            )(stacked, self.constrain_clients(train_x),
-              self.constrain_clients(ys),
-              self.constrain_clients(keys))
+            keys = jax.random.split(key, self.data.n_clients)
+            return self.map_clients(
+                lambda p, x, y, k: one_client_epochs(p, x, y, k, epochs),
+                stacked, self.client_data()["train"][0], ys, keys)
 
         # label-parameterized variant for data-level attacks (DESIGN.md
         # §15): same trace, with the (N, n_train) label table an argument
-        # instead of a closure constant
         self.train_fn_with_labels = train_fn_with_labels
 
         def train_fn(stacked, key, epochs):
-            return train_fn_with_labels(stacked, key, epochs, train_y)
+            return train_fn_with_labels(stacked, key, epochs,
+                                        self.client_data()["train"][1])
 
         self.train_fn = train_fn
         # local_train(stacked, key, epochs) -> (stacked', (N,) mean loss):
         # `epochs` seeded epochs of minibatch SGD vmapped over clients
         # (stacked leaves (N, ...); per-client streams fold_in by row)
-        self.local_train = jax.jit(train_fn, static_argnames=("epochs",))
-        self.local_train_with_labels = jax.jit(
+        self.local_train = self.jit(train_fn, static_argnames=("epochs",))
+        self.local_train_with_labels = self.jit(
             train_fn_with_labels, static_argnames=("epochs",))
 
         def eval_split_fn(stacked, xs, ys):
-            stacked = jax.tree.map(self.constrain_clients, stacked)
-            xs = self.constrain_clients(xs)
-            ys = self.constrain_clients(ys)
-            return (jax.vmap(lambda p, x, y: self.acc_fn(p, {"x": x, "y": y}))
-                    (stacked, xs, ys),
-                    jax.vmap(lambda p, x, y: loss_fn(p, {"x": x, "y": y}))
-                    (stacked, xs, ys))
+            def one(p, x, y):
+                batch = {"x": x, "y": y}
+                return self.acc_fn(p, batch), loss_fn(p, batch)
+
+            return self.map_clients(one, stacked, xs, ys)
 
         self.eval_split_fn = eval_split_fn
         self._eval_split = jax.jit(eval_split_fn)
 
-        val_x, val_y = self.val_data
-
         def eval_val_fn(stacked):
-            return eval_split_fn(stacked, val_x, val_y)
+            return eval_split_fn(stacked, *self.client_data()["val"])
 
         self.eval_val_fn = eval_val_fn
 
@@ -225,13 +272,60 @@ class FLEngine:
 
     def make_reward_fn(self):
         """reward(flat_params, k) = -validation loss of client k (Eq. 7)."""
-        val_x = jnp.asarray(self.data.val_x)
-        val_y = jnp.asarray(self.data.val_y)
         unravel = self._unravel
         loss_fn = self.loss_fn
 
         def reward(flat, k):
-            params = unravel(flat)
-            return -loss_fn(params, {"x": val_x[k], "y": val_y[k]})
+            val_x, val_y = self.client_data()["val"]
+            return -loss_fn(unravel(flat), {"x": val_x[k], "y": val_y[k]})
 
         return reward
+
+
+class DataJit:
+    """``jax.jit(fn)`` whose compiled program takes the engine's client
+    data (`FLEngine.client_data`) as a leading argument that is never
+    donated, bound for the duration of ``fn``'s trace. Called like ``fn``
+    (``__call__``, ``lower`` and ``eval_shape`` pass the data themselves),
+    so a ``round_step`` built on it keeps the ``round_step(state)``
+    contract, and the dataset is neither re-uploaded per dispatch nor
+    compiled into the program as constants. ``donate_argnums`` and
+    ``in_shardings`` count ``fn``'s own positional arguments; on a mesh
+    the data keeps the
+    shardings `FLEngine` placed it with."""
+
+    def __init__(self, engine, fn, *, donate_argnums=(), in_shardings=None,
+                 out_shardings=None, static_argnames=()):
+        self.engine = engine
+
+        def with_data(data, *args, **kwargs):
+            with engine._bind(data):
+                return fn(*args, **kwargs)
+
+        # fn's own signature behind the data argument, so static
+        # arguments resolve by name and position as they would for fn
+        sig = inspect.signature(fn)
+        with_data.__signature__ = sig.replace(parameters=[
+            inspect.Parameter("data", inspect.Parameter.POSITIONAL_ONLY),
+            *sig.parameters.values()])
+        kw = {"donate_argnums": tuple(i + 1 for i in donate_argnums),
+              "static_argnames": static_argnames}
+        if in_shardings is not None:
+            data_sh = jax.tree.map(lambda a: a.sharding, engine._data)
+            kw["in_shardings"] = (data_sh,) + tuple(in_shardings)
+        if out_shardings is not None:
+            kw["out_shardings"] = out_shardings
+        self._jit = jax.jit(with_data, **kw)
+
+    def __call__(self, *args, **kwargs):
+        return self._jit(self.engine.client_data(), *args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        return self._jit.lower(self.engine.client_data(), *args, **kwargs)
+
+    def eval_shape(self, *args, **kwargs):
+        return self._jit.eval_shape(self.engine.client_data(), *args,
+                                    **kwargs)
+
+    def _cache_size(self) -> int:
+        return self._jit._cache_size()

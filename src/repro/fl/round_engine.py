@@ -263,7 +263,8 @@ def make_round_step(engine, *, tau: int,
     When ``engine.mesh`` is set (`FLEngine.shard_clients`), the jit is
     built with `round_state_shardings` as ``in_shardings``/``out_shardings``
     so the client axis stays sharded across rounds with no resharding at
-    dispatch boundaries.
+    dispatch boundaries. The step is an `FLEngine.jit`: the client data
+    enters the compiled program as arguments that are never donated.
     """
     lt = local_train if local_train is not None else engine.train_fn
     if aggregate is not None and not _touches_exchange_site(aggregate):
@@ -322,14 +323,13 @@ def make_round_step(engine, *, tau: int,
             val_hist=val_hist,
             aux=aux)
 
-    mesh = getattr(engine, "mesh", None)
     dn = (0,) if donate else ()
-    if mesh is None:
-        return jax.jit(round_step, donate_argnums=dn)
-    sh = round_state_shardings(mesh, engine.client_axes, hist_len=hist_len,
-                               aux_specs=aux_specs)
-    return jax.jit(round_step, in_shardings=(sh,), out_shardings=sh,
-                   donate_argnums=dn)
+    if engine.mesh is None:
+        return engine.jit(round_step, donate_argnums=dn)
+    sh = round_state_shardings(engine.mesh, engine.client_axes,
+                               hist_len=hist_len, aux_specs=aux_specs)
+    return engine.jit(round_step, in_shardings=(sh,), out_shardings=sh,
+                      donate_argnums=dn)
 
 
 def run_rounds(round_step, state: RoundState, rounds: int,

@@ -22,7 +22,9 @@ from .sparse_graph_mix import sparse_graph_mix as _sparse_mix
 from .ssd import ssd as _ssd
 
 
-def _impl(impl: Optional[str]) -> str:
+def resolve_impl(impl: Optional[str]) -> str:
+    """The kernel implementation a call with ``impl`` runs: 'pallas',
+    'interpret' or 'ref'."""
     if impl:
         return impl
     env = os.environ.get("REPRO_KERNEL_IMPL")
@@ -42,7 +44,7 @@ def graph_mix(A, W, impl: Optional[str] = None, *, mesh=None,
     fp32 accumulation is preserved shard-for-shard and the gather is the
     round's only model-sized collective (DESIGN.md §8).
     """
-    m = _impl(impl)
+    m = resolve_impl(impl)
 
     def local(a, w):
         if m == "ref":
@@ -53,8 +55,6 @@ def graph_mix(A, W, impl: Optional[str] = None, *, mesh=None,
         return local(A, W)
     from jax.sharding import PartitionSpec as P
 
-    from ..sharding.compat import shard_map
-
     ca = tuple(client_axes)
 
     def row_block(a_blk, w_blk):
@@ -62,9 +62,9 @@ def graph_mix(A, W, impl: Optional[str] = None, *, mesh=None,
         return local(a_blk, w_full)
 
     # check_vma=False: pallas_call has no shard_map replication rule
-    return shard_map(row_block, mesh=mesh,
-                     in_specs=(P(ca, None), P(ca, None)),
-                     out_specs=P(ca, None), check_vma=False)(A, W)
+    return jax.shard_map(row_block, mesh=mesh,
+                         in_specs=(P(ca, None), P(ca, None)),
+                         out_specs=P(ca, None), check_vma=False)(A, W)
 
 
 @exchange_site(charges="caller")
@@ -82,7 +82,7 @@ def compressed_graph_mix(A, vals, idx, p_dim: int,
     point of sparsifying the exchange; each shard then computes its own
     row-block with the dispatched kernel.
     """
-    m = _impl(impl)
+    m = resolve_impl(impl)
 
     def local(a, v, i):
         if m == "ref":
@@ -94,8 +94,6 @@ def compressed_graph_mix(A, vals, idx, p_dim: int,
         return local(A, vals, idx)
     from jax.sharding import PartitionSpec as P
 
-    from ..sharding.compat import shard_map
-
     ca = tuple(client_axes)
 
     def row_block(a_blk, v_blk, i_blk):
@@ -104,9 +102,9 @@ def compressed_graph_mix(A, vals, idx, p_dim: int,
         return local(a_blk, v_full, i_full)
 
     # check_vma=False: pallas_call has no shard_map replication rule
-    return shard_map(row_block, mesh=mesh,
-                     in_specs=(P(ca, None), P(ca, None), P(ca, None)),
-                     out_specs=P(ca, None), check_vma=False)(A, vals, idx)
+    return jax.shard_map(row_block, mesh=mesh,
+                         in_specs=(P(ca, None), P(ca, None), P(ca, None)),
+                         out_specs=P(ca, None), check_vma=False)(A, vals, idx)
 
 
 def _rotation_schedule(mesh, client_axes):
@@ -158,7 +156,7 @@ def sparse_graph_mix(self_w, nbr_w, nbr_idx, W_self, peer_parts=None,
     requested — the exchange is list-shaped, like the decentralized
     system it simulates.
     """
-    m = _impl(impl)
+    m = resolve_impl(impl)
     if peer_parts is None:
         peer_parts = (W_self,)
     if peer_decode is None:
@@ -174,8 +172,6 @@ def sparse_graph_mix(self_w, nbr_w, nbr_idx, W_self, peer_parts=None,
         return local(self_w, nbr_w, nbr_idx, W_self,
                      peer_decode(*peer_parts))
     from jax.sharding import PartitionSpec as P
-
-    from ..sharding.compat import shard_map
 
     ca = tuple(client_axes)
     sizes, schedule = _rotation_schedule(mesh, ca)
@@ -216,7 +212,7 @@ def sparse_graph_mix(self_w, nbr_w, nbr_idx, W_self, peer_parts=None,
     part_specs = tuple(P(ca, *((None,) * (x.ndim - 1)))
                        for x in peer_parts)
     # check_vma=False: pallas_call has no shard_map replication rule
-    return shard_map(
+    return jax.shard_map(
         row_block, mesh=mesh,
         in_specs=(P(ca), P(ca, None), P(ca, None), P(ca, None))
         + part_specs,
@@ -226,7 +222,7 @@ def sparse_graph_mix(self_w, nbr_w, nbr_idx, W_self, peer_parts=None,
 
 def flash_attention(q, k, v, *, causal=True, window=None,
                     impl: Optional[str] = None, **kw):
-    m = _impl(impl)
+    m = resolve_impl(impl)
     if m == "ref":
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return _flash(q, k, v, causal=causal, window=window,
@@ -234,7 +230,7 @@ def flash_attention(q, k, v, *, causal=True, window=None,
 
 
 def rglru_scan(a, b, h0=None, impl: Optional[str] = None, **kw):
-    m = _impl(impl)
+    m = resolve_impl(impl)
     if m == "ref":
         return ref.linear_scan_ref(a, b, h0)
     return _rglru_scan(a, b, h0, interpret=(m == "interpret"), **kw)
@@ -242,7 +238,7 @@ def rglru_scan(a, b, h0=None, impl: Optional[str] = None, **kw):
 
 def ssd(x, dlogA, B, C, chunk: int = 256, h0=None,
         impl: Optional[str] = None, **kw):
-    m = _impl(impl)
+    m = resolve_impl(impl)
     if m == "ref":
         return ref.ssd_ref(x, dlogA, B, C, chunk, h0)
     return _ssd(x, dlogA, B, C, chunk=chunk, h0=h0,

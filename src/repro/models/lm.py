@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from ..sharding.compat import shard_map
 from .common import (NEG_INF, apply_rope, attention_ref, chunked_softmax_xent,
                      dense_init, embed_init, rms_norm, swiglu)
 from .moe import init_moe, moe_apply
@@ -98,7 +97,7 @@ def attn_decode_seqshard(q, k_new, v_new, cache, pos, cfg: ArchConfig,
     bq = P(da if da else None, None, None, None)
     ckv = P(da if da else None, "model", None, None)
     cpos_spec = P(da if da else None, "model")
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(bq, bq, bq, ckv, ckv, cpos_spec),
         out_specs=(bq, ckv, ckv, cpos_spec), check_vma=False)

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..sharding.compat import shard_map
 from .common import dense_init
 
 
@@ -136,7 +135,7 @@ def moe_apply(p, x, cfg, mesh=None, data_axes=("data",), impl="capacity"):
         return jax.lax.psum(y.reshape(Bl, Sl, dl), "model")
 
     dspec = P(tuple(data_axes)) if data_axes else P()
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(dspec, P("model"), P("model"), P("model"), dspec, dspec),
         out_specs=dspec, check_vma=False)

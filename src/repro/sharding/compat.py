@@ -1,90 +1,29 @@
-"""Version-compat constructors for jax mesh APIs.
+"""Mesh constructors for the installed jax (see `pyproject.toml`).
 
-The mesh surface moved across jax releases: `AbstractMesh` switched from a
-``((name, size), ...)`` shape_tuple to separate ``axis_sizes/axis_names``
-arguments, ``AxisType`` only exists on newer releases, and
-``jax.make_mesh`` grew (then required) an ``axis_types`` kwarg. Every mesh
-in this repo is built through these two helpers so a jax upgrade is a
-one-file audit (ISSUE 1 satellite; DESIGN.md §6).
+Every mesh in this repo is built through these helpers so that all of
+its axes are Auto-typed: `jax.make_mesh` defaults to Explicit axes, and
+the engine's sharding constraints and `jax.shard_map` blocks are written
+for Auto ones (DESIGN.md §6).
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import AbstractMesh
-
-try:  # jax >= 0.4.38
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # older jax: meshes are implicitly 'auto'
-    _AxisType = None
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """`jax.shard_map` across releases: the top-level export (with its
-    ``check_vma`` kwarg) when present, else the experimental one (whose
-    equivalent kwarg is ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _esm
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+from jax.sharding import AbstractMesh, AxisType
 
 
 def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
-    """AbstractMesh from parallel (sizes, names) tuples, e.g.
+    """Auto-typed AbstractMesh from parallel (sizes, names) tuples, e.g.
     ``abstract_mesh((16, 16), ("data", "model"))``."""
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:  # newer signature: (axis_sizes, axis_names)
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names),
+                        axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_mesh(axis_sizes, axis_names, **kw):
-    """`jax.make_mesh` with all axes Auto-typed when the running jax
-    supports axis types, and without the kwarg when it does not."""
-    if _AxisType is not None:
-        kw.setdefault("axis_types", (_AxisType.Auto,) * len(axis_names))
-    try:
-        return jax.make_mesh(tuple(axis_sizes), tuple(axis_names), **kw)
-    except TypeError:  # this jax has no axis_types kwarg
-        kw.pop("axis_types", None)
-        return jax.make_mesh(tuple(axis_sizes), tuple(axis_names), **kw)
-
-
-def _register_barrier_batching():
-    """Older jax releases ship `optimization_barrier` without a batching
-    rule, which breaks its use inside vmapped scans (the rule is the
-    obvious one: the barrier is an elementwise identity, so bind the
-    batched operands unchanged and keep their batch dims). Registration
-    must happen before any vmap trace — scan batching is deferred, so a
-    lazy try/except at the call site fires too late."""
-    try:
-        from jax._src.lax import lax as _lax_internal
-        from jax.interpreters import batching as _batching
-    except ImportError:  # internals moved: assume the rule exists
-        return
-    prim = getattr(_lax_internal, "optimization_barrier_p", None)
-    if prim is None or prim in _batching.primitive_batchers:
-        return
-
-    def _batch_rule(args, dims):
-        return prim.bind(*args), dims
-
-    _batching.primitive_batchers[prim] = _batch_rule
-
-
-_register_barrier_batching()
-
-
-def optimization_barrier(x):
-    """`jax.lax.optimization_barrier`, safe under `vmap` on every
-    supported jax release (see `_register_barrier_batching`)."""
-    return jax.lax.optimization_barrier(x)
+    """`jax.make_mesh` with all axes Auto-typed."""
+    kw.setdefault("axis_types", (AxisType.Auto,) * len(axis_names))
+    return jax.make_mesh(tuple(axis_sizes), tuple(axis_names), **kw)
 
 
 def mesh_axis_sizes(mesh) -> dict:
-    """{axis name: size} for Mesh and AbstractMesh across versions."""
-    sizes = getattr(mesh, "axis_sizes", None)
-    if sizes is None:
-        sizes = mesh.devices.shape
-    return dict(zip(mesh.axis_names, sizes))
+    """{axis name: size} of a Mesh or AbstractMesh."""
+    return dict(mesh.shape)

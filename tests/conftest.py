@@ -1,21 +1,7 @@
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
 # real single CPU device; only launch/dryrun.py forces 512 host devices.
-import importlib.util
-import sys
-
 import numpy as np
 import pytest
-
-# Property tests use hypothesis (declared in pyproject's dev extra). In
-# hermetic environments without it, register the minimal seeded-sweep
-# fallback under the same module name BEFORE test modules import it.
-if importlib.util.find_spec("hypothesis") is None:
-    import os
-
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_fallback
-
-    sys.modules.setdefault("hypothesis", _hypothesis_fallback)
 
 
 @pytest.fixture(scope="session")

@@ -17,7 +17,7 @@ DOCS = ["README.md", "docs/API.md"]
 # `python -m <module>` or `python <script>.py` at the start of a shell
 # command (env-var prefixes like XLA_FLAGS=... allowed before `python`)
 _CMD = re.compile(r"python (?:-m ([\w.]+)|((?:examples|benchmarks)"
-                  r"/[\w/]+\.py))")
+                  r"/[\w/]+\.py|chip_smoke\.py))")
 _FLAG = re.compile(r"--[A-Za-z][A-Za-z0-9-]*")
 
 
@@ -57,7 +57,7 @@ def _accepted_flags(entry):
     in a subprocess (entrypoints parse inside main(), and fl_dryrun must
     set XLA_FLAGS before its jax import — only --help is faithful)."""
     cmd = [sys.executable]
-    if "/" in entry:
+    if entry.endswith(".py"):
         cmd += [entry]
     else:
         cmd += ["-m", entry]

@@ -88,12 +88,12 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 import sys
 sys.path.insert(0, "src")
 from repro.roofline.hlo import analyze_hlo_text
-from repro.sharding.compat import make_mesh, shard_map
+from repro.sharding.compat import make_mesh
 
 mesh = make_mesh((4,), ("d",))
 def f(x):
     def body(c, _):
-        s = shard_map(lambda a: jax.lax.psum(a, "d"), mesh=mesh,
+        s = jax.shard_map(lambda a: jax.lax.psum(a, "d"), mesh=mesh,
                           in_specs=P("d"), out_specs=P("d"))(c)
         return c + s * 0.1, None
     y, _ = jax.lax.scan(body, x, None, length=5)

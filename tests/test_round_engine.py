@@ -136,8 +136,8 @@ def test_random_graph_comm_accounting(small_setting):
 
 def test_vmapped_bggc_matches_sequential_loop(small_setting):
     """The compiled all-clients BGGC (one traced program) selects exactly
-    what the old N-eager-calls python loop selected — same fold_in(key, k)
-    streams, bitwise-identical Omega."""
+    what a python loop of N single-client calls selects — same
+    fold_in(key, k) streams, bitwise-identical Omega."""
     eng = small_setting
     N = _TOY_N
     reward = eng.make_reward_fn()
@@ -150,12 +150,12 @@ def test_vmapped_bggc_matches_sequential_loop(small_setting):
     full_mask = jnp.ones((N, N), bool)
     k_graph = jax.random.PRNGKey(11)
     for budget in (2, 4):
-        bggc = make_bggc(reward, budget)
+        bggc = eng.jit(make_bggc(reward, budget))
         loop = jnp.stack([
             bggc(jax.random.fold_in(k_graph, k), jnp.int32(k),
                  full_mask[k], flat, eng.p)
             for k in range(N)])
-        vmapped = jax.jit(lambda kk, f, b=budget: all_clients_bggc(
+        vmapped = eng.jit(lambda kk, f, b=budget: all_clients_bggc(
             kk, f, eng.p, full_mask, reward, b))(k_graph, flat)
         np.testing.assert_array_equal(np.asarray(vmapped), np.asarray(loop),
                                       err_msg=f"budget={budget}")
@@ -320,3 +320,44 @@ def test_init_round_state_dealiases_aliased_leaves(small_setting):
     step = make_round_step(eng, tau=1, donate=True)
     out = step(st)
     assert int(out.t) == 1
+
+
+def test_round_step_takes_client_data_as_undonated_arguments():
+    """The client data enter the compiled round_step as arguments, never
+    as constants folded into the program: the lowered program does not
+    grow with the dataset, and a donating step donates the state only."""
+    sizes = []
+    for n in (16, 256):
+        data = make_federated_classification(
+            seed=0, n_clients=4, feature_dim=16, n_train=n, n_val=n,
+            n_test=8)
+        eng = FLEngine(MLP(16, 32, 10), data, lr=0.05, batch_size=8)
+        key = jax.random.PRNGKey(0)
+        state = init_round_state(eng.flatten(eng.init_clients(key)), key)
+        lowered = make_round_step(eng, tau=1, donate=True).lower(state)
+        (data_info, state_info), _ = lowered.args_info
+        assert not any(a.donated for a in jax.tree.leaves(data_info))
+        assert all(a.donated for a in jax.tree.leaves(state_info))
+        sizes.append(len(lowered.as_text()))
+    # 2 x 4 x 240 x 16 more fp32 values would add >100 KB as constants
+    assert abs(sizes[1] - sizes[0]) < 0.01 * sizes[0], sizes
+
+
+@pytest.mark.parametrize("wrap", ["jit", "vmap"])
+def test_client_data_refuses_a_trace_engine_jit_did_not_start(
+        small_setting, wrap):
+    """A plain ``jax.jit`` (or any other trace) over a fn that reads the
+    client data would fold the dataset into the program as constants:
+    the read raises there, and the same fn runs under `FLEngine.jit`."""
+    eng = small_setting
+    reward = eng.make_reward_fn()
+    flat = eng.flatten(eng.init_clients(jax.random.PRNGKey(0)))
+    ks = jnp.arange(_TOY_N)
+    traced = {"jit": jax.jit(lambda f: reward(f[0], 0)),
+              "vmap": lambda f: jax.vmap(reward)(f, ks)}[wrap]
+    with pytest.raises(RuntimeError, match="engine.jit"):
+        traced(flat)
+    want = eng.jit(jax.vmap(reward))(flat, ks)
+    np.testing.assert_array_equal(
+        np.asarray(eng.jit(lambda f: traced(f))(flat)).reshape(-1),
+        np.asarray(want)[:1 if wrap == "jit" else _TOY_N])

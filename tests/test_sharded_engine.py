@@ -214,8 +214,8 @@ for pods in (1, 2):  # single client axis AND the 2D (pod, data) torus
         sw = jax.random.normal(jax.random.fold_in(key, 3), (N,))
         want = np.asarray(sparse_graph_mix_ref(sw, nw, idx, W, W))
         for impl in ["ref", "interpret"]:
-            got = np.asarray(ops.sparse_graph_mix(
-                sw, nw, idx, W, impl=impl, mesh=mesh, client_axes=ca))
+            got = np.asarray(jax.jit(lambda *a: ops.sparse_graph_mix(
+                *a, impl=impl, mesh=mesh, client_axes=ca))(sw, nw, idx, W))
             err = np.abs(got - want).max()
             assert err < 1e-5, (pods, N, B, P, impl, err)
             print("OK", pods, N, B, P, impl)
@@ -229,9 +229,9 @@ for pods in (1, 2):  # single client axis AND the 2D (pod, data) torus
     tv = jnp.take_along_axis(W, tid, axis=1)
     dec = densify_topk(tv, tid.astype(jnp.int32), P)
     want = np.asarray(sparse_graph_mix_ref(sw, nw, idx, W, dec))
-    got = np.asarray(ops.sparse_graph_mix(
-        sw, nw, idx, W, (tv, tid.astype(jnp.int32)),
-        lambda v, i: densify_topk(v, i, P), mesh=mesh, client_axes=ca))
+    got = np.asarray(jax.jit(lambda sw, nw, idx, W, v, i: ops.sparse_graph_mix(
+        sw, nw, idx, W, (v, i), lambda v, i: densify_topk(v, i, P),
+        mesh=mesh, client_axes=ca))(sw, nw, idx, W, tv, tid.astype(jnp.int32)))
     assert np.abs(got - want).max() < 1e-5, pods
     print("OK", pods, "topk-parts")
     # int8-style parts: the (N,) fp32 scale rides the rotation as a 1-D
@@ -240,10 +240,10 @@ for pods in (1, 2):  # single client axis AND the 2D (pod, data) torus
     s = jnp.abs(jax.random.normal(jax.random.fold_in(key, 7), (N,)))
     dec8 = q.astype(jnp.float32) * s[:, None]
     want = np.asarray(sparse_graph_mix_ref(sw, nw, idx, W, dec8))
-    got = np.asarray(ops.sparse_graph_mix(
+    got = np.asarray(jax.jit(lambda sw, nw, idx, W, q, s: ops.sparse_graph_mix(
         sw, nw, idx, W, (q, s),
         lambda qq, ss: qq.astype(jnp.float32) * ss[:, None],
-        mesh=mesh, client_axes=ca))
+        mesh=mesh, client_axes=ca))(sw, nw, idx, W, q, s))
     assert np.abs(got - want).max() < 1e-5, pods
     print("OK", pods, "int8-parts")
 """
@@ -253,7 +253,9 @@ def test_sparse_mix_rotation_matches_ref():
     """The neighbor-list mix's shard_map path — peer panels rotated
     shard-to-shard via ppermute, only requested rows kept (DESIGN.md
     §12) — equals the single-device oracle on 1D and 2D client meshes,
-    for raw, topk and int8 peer parts, under both kernel impls."""
+    for raw, topk and int8 peer parts, under both kernel impls. Jitted,
+    as the round engine calls it (an eager shard_map dispatches every
+    op per device)."""
     r = _run(SPARSE_MIX_CODE)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     assert r.stdout.count("OK") == 16

@@ -145,11 +145,11 @@ def test_sparse_ggc_bitwise_matches_dense(small_setting):
             cand[k, rng.choice(others, min(budget, N - 1),
                                replace=False)] = True
         candj = jnp.asarray(cand)
-        dense = all_clients_graph(jax.random.PRNGKey(1), flat, eng.p,
-                                  candj, reward, budget)
-        sp = all_clients_graph_sparse(
-            jax.random.PRNGKey(1), flat, eng.p,
-            neighbors_from_adjacency(candj, budget), reward, budget)
+        dense = eng.jit(lambda f, c, b=budget: all_clients_graph(
+            jax.random.PRNGKey(1), f, eng.p, c, reward, b))(flat, candj)
+        sp = eng.jit(lambda f, c, b=budget: all_clients_graph_sparse(
+            jax.random.PRNGKey(1), f, eng.p,
+            neighbors_from_adjacency(c, b), reward, b))(flat, candj)
         np.testing.assert_array_equal(
             np.asarray(dense | jnp.eye(N, dtype=bool)),
             np.asarray(adjacency_from_neighbors(sp, N)),
@@ -166,11 +166,13 @@ def test_sparse_ggc_active_matches_dense_restriction(small_setting):
     reward = eng.make_reward_fn()
     cand = jnp.asarray(~np.eye(N, dtype=bool))
     active = jnp.asarray(np.array([1, 0, 1, 1, 0, 1], bool))
-    dense = all_clients_graph(jax.random.PRNGKey(2), flat, eng.p,
-                              cand & active[None, :], reward, 3)
-    sp = all_clients_graph_sparse(
-        jax.random.PRNGKey(2), flat, eng.p,
-        neighbors_from_adjacency(cand, N - 1), reward, 3, active=active)
+    dense = eng.jit(lambda f, c, a: all_clients_graph(
+        jax.random.PRNGKey(2), f, eng.p, c & a[None, :], reward, 3))(
+            flat, cand, active)
+    sp = eng.jit(lambda f, c, a: all_clients_graph_sparse(
+        jax.random.PRNGKey(2), f, eng.p,
+        neighbors_from_adjacency(c, N - 1), reward, 3, active=a))(
+            flat, cand, active)
     d = np.asarray(dense | jnp.eye(N, dtype=bool))
     s = np.asarray(adjacency_from_neighbors(sp, N))
     act = np.asarray(active)
@@ -185,10 +187,11 @@ def test_sparse_bggc_bitwise_matches_dense(small_setting):
     flat = _trained_flat(eng)
     reward = eng.make_reward_fn()
     for budget in (2, 4):
-        dense = all_clients_bggc(jax.random.PRNGKey(11), flat, eng.p,
-                                 jnp.ones((N, N), bool), reward, budget)
-        sp = all_clients_bggc_sparse(jax.random.PRNGKey(11), flat, eng.p,
-                                     reward, budget)
+        dense = eng.jit(lambda f, b=budget: all_clients_bggc(
+            jax.random.PRNGKey(11), f, eng.p, jnp.ones((N, N), bool),
+            reward, b))(flat)
+        sp = eng.jit(lambda f, b=budget: all_clients_bggc_sparse(
+            jax.random.PRNGKey(11), f, eng.p, reward, b))(flat)
         np.testing.assert_array_equal(
             np.asarray(dense | jnp.eye(N, dtype=bool)),
             np.asarray(adjacency_from_neighbors(sp, N)),
