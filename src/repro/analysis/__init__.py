@@ -19,6 +19,9 @@ Layers:
   jitted round_step, attributes collective wire bytes from the
   post-SPMD HLO, and reconciles them against the claimed
   ``DPFLResult.comm_bytes``.
+* :mod:`repro.analysis.counters` — trace-time counters of a compiled
+  program's work (the GGC reward probes), collected per program by
+  ``FLEngine.jit`` into ``program.counts``.
 
 The linter layers and the registry are dependency-free (stdlib only) so
 the CLI runs without importing jax; ``guards`` and ``commaudit`` import
@@ -32,13 +35,14 @@ _GUARD_EXPORTS = (
 _REGISTRY_EXPORTS = ("exchange_site", "is_exchange_site", "EXCHANGE_SITES",
                      "ExchangeSite")
 
-__all__ = (["tracelint", "fedlint", "registry", "commaudit"]
+__all__ = (["tracelint", "fedlint", "registry", "commaudit", "counters"]
            + list(_GUARD_EXPORTS) + list(_REGISTRY_EXPORTS))
 
 
 def __getattr__(name):
     import importlib
-    if name in ("guards", "tracelint", "fedlint", "registry", "commaudit"):
+    if name in ("guards", "tracelint", "fedlint", "registry", "commaudit",
+                "counters"):
         return importlib.import_module(f".{name}", __name__)
     if name in _GUARD_EXPORTS:
         mod = importlib.import_module(".guards", __name__)

@@ -127,6 +127,11 @@ class DPFLResult:
     # clients under partial participation
     comm_downloads: list = field(default_factory=list)  # per-round totals
     comm_preprocess: int = 0
+    # reward probes the GGC refresh EXECUTED each round (0 on rounds that
+    # do not refresh): 4 x N x scan length, the N^2 scan of the dense
+    # representation and the N x B scan of the sparse one, whatever the
+    # candidate sets hold (`repro.analysis.counters`, "ggc.probes")
+    ggc_probes: list = field(default_factory=list)      # per-round totals
     # byte-level accounting (DESIGN.md §11): every download moves one
     # encoded model, so bytes = downloads x the codec's static wire size
     # (`compress.bytes_per_model`) — exact python-int arithmetic at any
@@ -248,17 +253,17 @@ def _cached_bggc(engine: FLEngine, cfg: DPFLConfig, reward_fn, budget: int):
         if sparse:
             # neighbor-list BGGC: full candidacy is implicit, no (N, N)
             # candidate table; emits the (N, B) Omega lists directly
-            def build(k_graph, flat, p):
+            def bggc(k_graph, flat, p):
                 return all_clients_bggc_sparse(
                     k_graph, flat, p, reward_fn, budget,
                     mix_impl=cfg.mix_impl, mesh=mesh, client_axes=ca)
         else:
-            def build(k_graph, flat, cand, p):
+            def bggc(k_graph, flat, cand, p):
                 return all_clients_bggc(k_graph, flat, p, cand, reward_fn,
                                         budget, mix_impl=cfg.mix_impl,
                                         mesh=mesh, client_axes=ca)
 
-        cache[key] = engine.jit(build)
+        cache[key] = engine.jit(bggc)
     return cache[key]
 
 
@@ -303,44 +308,51 @@ def _preprocess(engine: FLEngine, cfg: DPFLConfig, reward_fn, budget: int):
     key = jax.random.PRNGKey(cfg.seed)
     k_init, k_pre, k_graph, k_train = jax.random.split(key, 4)
 
-    stacked = engine.init_clients(k_init)
-    stacked, _ = engine.local_train(stacked, k_pre, epochs=cfg.tau_init)
-    flat = engine.flatten(stacked)
+    # host spans of the three stages on the profiler's host plane (each
+    # encloses the stage's dispatch; the device may still be running it)
+    with jax.profiler.TraceAnnotation("dpfl.preprocess.train"):
+        stacked = engine.init_clients(k_init)
+        stacked, _ = engine.local_train(stacked, k_pre,
+                                        epochs=cfg.tau_init)
+        flat = engine.flatten(stacked)
 
     sparse = _sparse(cfg)
-    if cfg.random_graph:
-        # Fig. 3 ablation: random Omega_k of size budget; both
-        # representations sample the SAME peer sets from the same rng
-        rng = np.random.default_rng(cfg.seed)
-        B = _nbr_width(N, budget)
-        omega = np.zeros((N, N), bool)
-        nbr = np.full((N, B), -1, np.int32)
-        for k_ in range(N):
-            others = np.setdiff1d(np.arange(N), [k_])
-            sel = rng.choice(others, size=min(budget, N - 1), replace=False)
-            omega[k_, sel] = True
-            omega[k_, k_] = True
-            nbr[k_, :len(sel)] = np.sort(sel)
-        omega = jnp.asarray(nbr) if sparse else jnp.asarray(omega)
-    elif sparse:
-        # BGGC emitting (N, B) Omega lists (no (N, N) table anywhere)
-        omega = _cached_bggc(engine, cfg, reward_fn, budget)(
-            k_graph, flat, p)
-    else:
-        # BGGC: batched preprocessing within the communication budget,
-        # compiled once for all clients (vmapped; sharded under a mesh)
-        omega = _cached_bggc(engine, cfg, reward_fn, budget)(
-            k_graph, flat, jnp.ones((N, N), bool), p)
+    with jax.profiler.TraceAnnotation("dpfl.preprocess.bggc"):
+        if cfg.random_graph:
+            # Fig. 3 ablation: random Omega_k of size budget; both
+            # representations sample the SAME peer sets from the same rng
+            rng = np.random.default_rng(cfg.seed)
+            B = _nbr_width(N, budget)
+            omega = np.zeros((N, N), bool)
+            nbr = np.full((N, B), -1, np.int32)
+            for k_ in range(N):
+                others = np.setdiff1d(np.arange(N), [k_])
+                sel = rng.choice(others, size=min(budget, N - 1),
+                                 replace=False)
+                omega[k_, sel] = True
+                omega[k_, k_] = True
+                nbr[k_, :len(sel)] = np.sort(sel)
+            omega = jnp.asarray(nbr) if sparse else jnp.asarray(omega)
+        elif sparse:
+            # BGGC emitting (N, B) Omega lists (no (N, N) table anywhere)
+            omega = _cached_bggc(engine, cfg, reward_fn, budget)(
+                k_graph, flat, p)
+        else:
+            # BGGC: batched preprocessing within the communication budget,
+            # compiled once for all clients (vmapped; sharded under a mesh)
+            omega = _cached_bggc(engine, cfg, reward_fn, budget)(
+                k_graph, flat, jnp.ones((N, N), bool), p)
 
-    if sparse:
-        self_w, nbr_w = sparse_mixing_weights(omega, p)
-        flat = mix_flat_sparse(self_w, nbr_w, omega, flat,
-                               impl=cfg.mix_impl, mesh=engine.mesh,
-                               client_axes=engine.client_axes)
-    else:
-        A = mixing_matrix(omega, p)
-        flat = mix_flat(A, flat, impl=cfg.mix_impl, mesh=engine.mesh,
-                        client_axes=engine.client_axes)
+    with jax.profiler.TraceAnnotation("dpfl.preprocess.mix"):
+        if sparse:
+            self_w, nbr_w = sparse_mixing_weights(omega, p)
+            flat = mix_flat_sparse(self_w, nbr_w, omega, flat,
+                                   impl=cfg.mix_impl, mesh=engine.mesh,
+                                   client_axes=engine.client_axes)
+        else:
+            A = mixing_matrix(omega, p)
+            flat = mix_flat(A, flat, impl=cfg.mix_impl, mesh=engine.mesh,
+                            client_axes=engine.client_axes)
     return omega, flat, k_graph, k_train
 
 
@@ -455,8 +467,9 @@ def _make_dpfl_aggregate(engine: FLEngine, cfg: DPFLConfig, reward_fn,
                         jax.random.fold_in(aux["k_graph"], 1000 + t), f, p,
                         omega, reward_fn, budget, impl=cfg.graph_impl,
                         mix_impl=cfg.mix_impl, mesh=mesh, client_axes=ca)
-            new_adj = jax.lax.cond(refresh, do_refresh, lambda f: adj,
-                                   probe_w)
+            with jax.named_scope("round.refresh"):
+                new_adj = jax.lax.cond(refresh, do_refresh,
+                                       lambda f: adj, probe_w)
         # recv = what row k receives from peer i: decoded payloads under
         # compression, the wire table under free-riding, flat otherwise
         recv = dec if comp is not None else wire
@@ -564,8 +577,9 @@ def _make_dpfl_aggregate_sparse(engine: FLEngine, cfg: DPFLConfig,
                     refreshed = jnp.where(active[:, None], refreshed, nbr)
                 return refreshed
 
-            new_nbr = jax.lax.cond(refresh, do_refresh, lambda f: nbr,
-                                   probe_w)
+            with jax.named_scope("round.refresh"):
+                new_nbr = jax.lax.cond(refresh, do_refresh,
+                                       lambda f: nbr, probe_w)
         # recv = peer-visible model table row k gathers from (decoded
         # payloads under compression, the wire table under free-riding)
         recv = dec if comp is not None else wire
@@ -747,6 +761,9 @@ def run_dpfl(engine: FLEngine, cfg: DPFLConfig) -> DPFLResult:
         flush_every=hist_len if (hist_len and cfg.history_every) else 0)
 
     result.comm_downloads = [int(c) for c in np.asarray(state.aux["comm"])]
+    probes = round_step.counts.get("ggc.probes", 0)
+    result.ggc_probes = [probes if t % cfg.refresh_period == 0 else 0
+                         for t in range(cfg.rounds)]
     _fill_comm_bytes(result, cfg, engine.n_params)
     best = engine.unflatten(state.best_flat)
     test_acc, _ = engine.eval_test(best)
@@ -845,14 +862,18 @@ def run_dpfl_reference(engine: FLEngine, cfg: DPFLConfig) -> DPFLResult:
         else:
             result.comm_downloads.append(
                 int(_realized_downloads(count_graph, active)))
+        probes = 0
         if cfg.random_graph:
             adj = omega
         elif refresh:
-            refreshed = _cached_refresh(engine, cfg, reward_fn, budget)(
+            refresh_fn = _cached_refresh(engine, cfg, reward_fn, budget)
+            refreshed = refresh_fn(
                 jax.random.fold_in(k_graph, 1000 + t), probe_w, p, omega,
                 active)
             adj = refreshed if active is None else \
                 jnp.where(active[:, None], refreshed, adj)
+            probes = refresh_fn.counts.get("ggc.probes", 0)
+        result.ggc_probes.append(probes)
         recv = dec if comp is not None else wire
         if sparse:
             if rule == "trimmed":

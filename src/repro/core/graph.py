@@ -34,6 +34,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..analysis.counters import count as _count
 from ..analysis.registry import exchange_site
 from ..kernels import ops as _kops
 
@@ -372,6 +373,15 @@ def _shard_clients_graph(per_client, mesh, client_axes, keys, ks,
                                                 flat_w, p, *extra)
 
 
+def _count_probes(n_clients: int, scan_len: int) -> None:
+    """Count, at trace time, the reward probes one call of an all-clients
+    builder executes: four per scan step (`greedy_decision_step`), a scan
+    of ``scan_len`` candidates for each of ``n_clients`` clients — N for
+    the dense scans and BGGC, B for the neighbor-list scan — however few
+    of them are candidates (`repro.analysis.counters`)."""
+    _count("ggc.probes", 4 * n_clients * scan_len)
+
+
 def all_clients_graph(key, flat_w, p, cand_masks, reward_fn, budget,
                       impl: str = "ggc", mix_impl: Optional[str] = None,
                       mesh=None, client_axes=None):
@@ -382,6 +392,7 @@ def all_clients_graph(key, flat_w, p, cand_masks, reward_fn, budget,
     ``mesh``/``client_axes`` the vmap covers only the shard-local k rows
     inside a shard_map (adjacency rows come back client-sharded)."""
     N = flat_w.shape[0]
+    _count_probes(N, N)
     if impl == "naive":
         ggc = make_ggc_naive(reward_fn, budget)
     else:
@@ -404,6 +415,7 @@ def all_clients_bggc(key, flat_w, p, cand_masks, reward_fn, budget,
     sequential loop (same fold_in(key, k) streams; tested). With
     ``mesh``/``client_axes``, the vmap covers only shard-local k rows."""
     N = flat_w.shape[0]
+    _count_probes(N, N)
     bggc = make_bggc(reward_fn, budget, mix_impl=mix_impl)
     keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(N))
     if mesh is not None:
@@ -590,6 +602,7 @@ def all_clients_graph_sparse(key, flat_w, p, cand_idx, reward_fn,
     candidate pool to available peers (absent-client handling — keeping
     the previous C_k — is the caller's, as in the dense path)."""
     N = flat_w.shape[0]
+    _count_probes(N, cand_idx.shape[1])
     ggc = make_ggc_sparse(reward_fn, budget, mix_impl=mix_impl)
     keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(N))
     extra = () if active is None else (active,)
@@ -615,6 +628,7 @@ def all_clients_bggc_sparse(key, flat_w, p, reward_fn, budget: int,
     (N, budget) Omega list. Selections equal `all_clients_bggc` with a
     full candidate mask, bitwise (tested)."""
     N = flat_w.shape[0]
+    _count_probes(N, N)
     bggc = make_bggc(reward_fn, budget, mix_impl=mix_impl)
     # list width: a client can select at most min(budget, N-1) peers, and
     # the round engine sizes every (N, B) buffer with the same clamp —
@@ -641,6 +655,7 @@ def all_clients_graph_heterogeneous(key, flat_w, p, cand_masks, reward_fn,
     from the paper's §Limitations). budgets: (N,) int32; reachability:
     (N, N) bool — client k may only ever talk to reachable peers."""
     N = flat_w.shape[0]
+    _count_probes(N, N)
     if reachability is not None:
         cand_masks = cand_masks & reachability
     ggc = make_ggc_heterogeneous(reward_fn, int(jnp.max(budgets)),

@@ -21,6 +21,7 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from ..analysis.counters import collecting
 from ..models.classifier import accuracy as _acc
 from ..models.classifier import xent_loss as _xent
 from ..optim import Optimizer, sgd
@@ -292,16 +293,30 @@ class DataJit:
     compiled into the program as constants. ``donate_argnums`` and
     ``in_shardings`` count ``fn``'s own positional arguments; on a mesh
     the data keeps the
-    shardings `FLEngine` placed it with."""
+    shardings `FLEngine` placed it with.
+
+    The compiled program takes ``fn``'s name (``jit_round_step``,
+    ``jit_train_fn``, ...), which names its module in a profiler trace.
+    ``counts`` holds the work its latest trace counted
+    (`repro.analysis.counters`), e.g. ``counts["ggc.probes"]``: the GGC
+    reward probes one call executes. The counts are static (from shapes,
+    at trace time), and only the latest trace's are kept: a program
+    traced at two signatures reports the one traced last."""
 
     def __init__(self, engine, fn, *, donate_argnums=(), in_shardings=None,
                  out_shardings=None, static_argnames=()):
         self.engine = engine
+        self.counts = {}
 
         def with_data(data, *args, **kwargs):
-            with engine._bind(data):
-                return fn(*args, **kwargs)
+            counts = {}
+            with engine._bind(data), collecting(counts):
+                out = fn(*args, **kwargs)
+            self.counts = counts
+            return out
 
+        with_data.__name__ = with_data.__qualname__ = getattr(
+            fn, "__name__", "with_data")
         # fn's own signature behind the data argument, so static
         # arguments resolve by name and position as they would for fn
         sig = inspect.signature(fn)

@@ -280,48 +280,58 @@ def make_round_step(engine, *, tau: int,
     agg_takes_prev = _accepts(agg, "prev")
 
     def round_step(state: RoundState) -> RoundState:
+        # named scopes mark the round's phases in the compiled program's
+        # op metadata (``op_name="jit(round_step)/round.train/..."``), so a
+        # profile attributes each device op to one phase; they change no
+        # arithmetic
         t = state.t
-        stacked = engine.unflatten(state.flat)
-        kt = jax.random.fold_in(state.key, t)
-        if lt_takes_aux:
-            stacked, _ = lt(stacked, kt, epochs=tau, aux=state.aux, t=t)
-        else:
-            stacked, _ = lt(stacked, kt, epochs=tau)
-        flat = engine.flatten(stacked)
-        if participation_key is not None:
-            # absent clients hold their round-start params; the schedule
-            # is client-sharded, so the select stays shard-local
-            m = state.aux[participation_key][t]
-            flat = jnp.where(m[:, None], flat, state.flat)
-        if post_train is not None:
-            # after the hold: an absent attacker's row is its round-start
-            # params either way, so poisoning composes with participation
-            flat = post_train(flat, state.flat, state.aux, t)
+        with jax.named_scope("round.train"):
+            stacked = engine.unflatten(state.flat)
+            kt = jax.random.fold_in(state.key, t)
+            if lt_takes_aux:
+                stacked, _ = lt(stacked, kt, epochs=tau, aux=state.aux, t=t)
+            else:
+                stacked, _ = lt(stacked, kt, epochs=tau)
+            flat = engine.flatten(stacked)
+            if participation_key is not None:
+                # absent clients hold their round-start params; the
+                # schedule is client-sharded, so the select stays
+                # shard-local
+                m = state.aux[participation_key][t]
+                flat = jnp.where(m[:, None], flat, state.flat)
+            if post_train is not None:
+                # after the hold: an absent attacker's row is its
+                # round-start params either way, so poisoning composes
+                # with participation
+                flat = post_train(flat, state.flat, state.aux, t)
         # barriers: keep the train -> aggregate -> eval stages fusion-
         # isolated so the fused round tracks the staged host loop (and the
         # mesh-sharded build tracks the single-device one) as closely as
         # XLA allows — cross-stage fusion reorders fp accumulation, which
         # the greedy graph decisions amplify (DESIGN.md §8)
         flat = jax.lax.optimization_barrier(flat)
-        if agg_takes_prev:
-            flat, aux = agg(flat, state.aux, t, prev=state.flat)
-        else:
-            flat, aux = agg(flat, state.aux, t)
+        # the aggregate's GGC refresh scopes itself ``round.refresh``
+        with jax.named_scope("round.mix"):
+            if agg_takes_prev:
+                flat, aux = agg(flat, state.aux, t, prev=state.flat)
+            else:
+                flat, aux = agg(flat, state.aux, t)
         flat = jax.lax.optimization_barrier(flat)
-        ev = eval_flat(flat, aux) if eval_flat is not None else flat
-        val_acc, _ = engine.eval_val_fn(engine.unflatten(ev))
-        improved = val_acc > state.best_val
-        val_hist = state.val_hist
-        if hist_len:
-            val_hist = val_hist.at[t % hist_len].set(val_acc)
-        return RoundState(
-            t=t + 1,
-            key=state.key,
-            flat=flat,
-            best_val=jnp.where(improved, val_acc, state.best_val),
-            best_flat=jnp.where(improved[:, None], ev, state.best_flat),
-            val_hist=val_hist,
-            aux=aux)
+        with jax.named_scope("round.eval"):
+            ev = eval_flat(flat, aux) if eval_flat is not None else flat
+            val_acc, _ = engine.eval_val_fn(engine.unflatten(ev))
+            improved = val_acc > state.best_val
+            val_hist = state.val_hist
+            if hist_len:
+                val_hist = val_hist.at[t % hist_len].set(val_acc)
+            return RoundState(
+                t=t + 1,
+                key=state.key,
+                flat=flat,
+                best_val=jnp.where(improved, val_acc, state.best_val),
+                best_flat=jnp.where(improved[:, None], ev, state.best_flat),
+                val_hist=val_hist,
+                aux=aux)
 
     dn = (0,) if donate else ()
     if engine.mesh is None:
@@ -343,12 +353,17 @@ def run_rounds(round_step, state: RoundState, rounds: int,
     (``guard_transfers=False`` opts out). ``on_flush(state, done)`` (if
     given) is invoked every ``flush_every`` rounds — inside an
     `allow_transfers` escape, since pulling history buffers off device is
-    its purpose — and once more at the end, outside the guarded region."""
+    its purpose — and once more at the end, outside the guarded region.
+
+    On the profiler's host plane each dispatch is a ``dpfl.round`` step
+    span (``step_num`` = its index in this call); with the profiler off
+    it costs one `jax.profiler.TraceAnnotation` enter and exit."""
     guard = no_transfer() if guard_transfers else contextlib.nullcontext()
     last = 0
     with guard:
         for t in range(rounds):
-            state = round_step(state)
+            with jax.profiler.StepTraceAnnotation("dpfl.round", step_num=t):
+                state = round_step(state)
             if flush_every and on_flush is not None and \
                     (t + 1) % flush_every == 0 and t + 1 < rounds:
                 with allow_transfers():

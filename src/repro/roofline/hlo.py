@@ -13,6 +13,9 @@ multiplies through ``known_trip_count`` annotations, producing:
                      all-reduce / reduce-scatter / all-to-all /
                      collective-permute, loop-multiplied
 
+`instruction_scopes` maps each instruction to the `jax.named_scope` phase
+it belongs to, which attributes a profile's device ops to phases.
+
 Shapes in post-SPMD HLO are per-partition, so every number here is
 per-device. This is an HBM *traffic model*, not a simulator — documented
 assumptions in EXPERIMENTS.md §Roofline.
@@ -517,3 +520,61 @@ def cross_pod_collective_bytes(text: str, pod_size: int = 256) -> dict:
 
     walk(m.entry, 1)
     return out
+
+
+# ------------------------------------------------- named-scope attribution
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def instruction_scopes(text_or_module,
+                       prefix: str) -> Dict[str, Optional[str]]:
+    """Instruction name -> the innermost `jax.named_scope` whose name
+    starts with ``prefix`` in the instruction's ``op_name`` metadata
+    (``op_name="jit(round_step)/round.mix/round.refresh/..."`` ->
+    ``round.refresh`` for prefix ``"round."``), for every instruction of
+    the compiled module: the map a profile needs to attribute each
+    device op, which it names but does not scope, to a phase.
+
+    An instruction XLA made itself carries no such scope (a broadcast or
+    iota hoisted out of a loop, a copy): it takes the scope of a
+    consumer that has one, else of the instruction that calls its
+    computation (a ``while`` or ``conditional``), else of an operand (a
+    copy of a program output), transitively. ``None`` where none gives
+    one."""
+    m = text_or_module if isinstance(text_or_module, HloModule) \
+        else HloModule(text_or_module)
+    scope: Dict[str, Optional[str]] = {}
+    comp_of: Dict[str, str] = {}
+    operands: Dict[str, List[str]] = {}
+    users: Dict[str, List[str]] = {}
+    caller: Dict[str, str] = {}
+    for comp, instrs in m.computations.items():
+        for i in instrs:
+            op_name = _OP_NAME.search(i.attrs)
+            parts = [p for p in (op_name.group(1).split("/") if op_name
+                                 else ()) if p.startswith(prefix)]
+            scope[i.name] = parts[-1] if parts else None
+            comp_of[i.name] = comp
+            operands[i.name] = i.operands
+            for o in i.operands:
+                users.setdefault(o, []).append(i.name)
+            for c in i.called:
+                caller.setdefault(c, i.name)
+    changed = True
+    while changed:
+        changed = False
+        for name, s in scope.items():
+            if s is not None:
+                continue
+            near = [scope[u] for u in users.get(name, ()) if scope[u]]
+            up = caller.get(comp_of[name])
+            if not near and up is not None and scope[up]:
+                near = [scope[up]]
+            if not near:
+                near = [scope[o] for o in operands[name]
+                        if scope.get(o)]
+            if near:
+                scope[name] = near[0]
+                changed = True
+    return scope
