@@ -128,9 +128,9 @@ class DPFLResult:
     comm_downloads: list = field(default_factory=list)  # per-round totals
     comm_preprocess: int = 0
     # reward probes the GGC refresh EXECUTED each round (0 on rounds that
-    # do not refresh): 4 x N x scan length, the N^2 scan of the dense
-    # representation and the N x B scan of the sparse one, whatever the
-    # candidate sets hold (`repro.analysis.counters`, "ggc.probes")
+    # do not refresh): 4 x N x scan length, the N x B list scan of either
+    # representation (the N^2 full scan for graph_impl="naive"), whatever
+    # the candidate sets hold (`repro.analysis.counters`, "ggc.probes")
     ggc_probes: list = field(default_factory=list)      # per-round totals
     # byte-level accounting (DESIGN.md §11): every download moves one
     # encoded model, so bytes = downloads x the codec's static wire size
@@ -291,7 +291,8 @@ def _cached_refresh(engine: FLEngine, cfg: DPFLConfig, reward_fn,
                     cand = cand & active[None, :]
                 return all_clients_graph(
                     k_graph, flat, p, cand, reward_fn, budget,
-                    impl=cfg.graph_impl, mix_impl=cfg.mix_impl)
+                    impl=cfg.graph_impl, mix_impl=cfg.mix_impl,
+                    width=_nbr_width(flat.shape[0], budget))
 
         cache[key] = engine.jit(refresh)
     return cache[key]
@@ -418,6 +419,9 @@ def _make_dpfl_aggregate(engine: FLEngine, cfg: DPFLConfig, reward_fn,
         adj = aux["adj"]
         omega = aux["omega"]
         N = adj.shape[0]
+        # Omega is _preprocess's BGGC or random graph: at most this many
+        # off-diagonal peers a row, so the refresh scans rows as lists
+        width = _nbr_width(N, budget)
         active = aux["part"][t] if part else None
         # the peer-visible upload table; trace-gated on a STATIC config
         # predicate so fraction=0.0 keeps the adversary-free trace
@@ -456,7 +460,7 @@ def _make_dpfl_aggregate(engine: FLEngine, cfg: DPFLConfig, reward_fn,
                         jax.random.fold_in(aux["k_graph"], 1000 + t), f, p,
                         omega & active[None, :], reward_fn, budget,
                         impl=cfg.graph_impl, mix_impl=cfg.mix_impl,
-                        mesh=mesh, client_axes=ca)
+                        mesh=mesh, client_axes=ca, width=width)
                     return jnp.where(active[:, None], refreshed, adj)
             else:
                 comm_t = jnp.where(refresh, jnp.sum(omega),
@@ -466,7 +470,8 @@ def _make_dpfl_aggregate(engine: FLEngine, cfg: DPFLConfig, reward_fn,
                     return all_clients_graph(
                         jax.random.fold_in(aux["k_graph"], 1000 + t), f, p,
                         omega, reward_fn, budget, impl=cfg.graph_impl,
-                        mix_impl=cfg.mix_impl, mesh=mesh, client_axes=ca)
+                        mix_impl=cfg.mix_impl, mesh=mesh, client_axes=ca,
+                        width=width)
             with jax.named_scope("round.refresh"):
                 new_adj = jax.lax.cond(refresh, do_refresh,
                                        lambda f: adj, probe_w)

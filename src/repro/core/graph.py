@@ -25,7 +25,9 @@ Every builder exists in two graph representations (DESIGN.md §12): the
 dense entry points emit (N, N) bool masks, the ``*_sparse`` ones emit
 (N, B) int32 neighbor lists (ascending peer ids, -1 pads, self edge
 implicit) whose greedy scans probe only the <= B candidates — same
-seeded decisions bit for bit, O(N·B) instead of O(N²) work.
+seeded decisions bit for bit, O(N·B) instead of O(N²) work. The dense
+GGC scans those lists too when its caller bounds the candidate rows
+(`all_clients_graph`'s ``width``), taking and returning masks.
 """
 from __future__ import annotations
 
@@ -377,21 +379,38 @@ def _count_probes(n_clients: int, scan_len: int) -> None:
     """Count, at trace time, the reward probes one call of an all-clients
     builder executes: four per scan step (`greedy_decision_step`), a scan
     of ``scan_len`` candidates for each of ``n_clients`` clients — N for
-    the dense scans and BGGC, B for the neighbor-list scan — however few
-    of them are candidates (`repro.analysis.counters`)."""
+    the full scans and BGGC, the list width for the neighbor-list scan —
+    however few of them are candidates (`repro.analysis.counters`)."""
     _count("ggc.probes", 4 * n_clients * scan_len)
 
 
 def all_clients_graph(key, flat_w, p, cand_masks, reward_fn, budget,
                       impl: str = "ggc", mix_impl: Optional[str] = None,
-                      mesh=None, client_axes=None):
+                      mesh=None, client_axes=None,
+                      width: Optional[int] = None):
     """Run graph construction for every client (vmap over k).
 
     cand_masks: (N, N) bool, row k = Omega_k. Returns adjacency (N, N) bool
     with adj[k, i]=1 iff i selected for k (diag True). With
     ``mesh``/``client_axes`` the vmap covers only the shard-local k rows
-    inside a shard_map (adjacency rows come back client-sharded)."""
+    inside a shard_map (adjacency rows come back client-sharded).
+
+    ``width`` is a static bound on the off-diagonal candidates of every
+    row. Given it, the ``"ggc"`` scan visits each Omega_k as a list of
+    ``width`` slots (`make_ggc_sparse`) instead of all N clients: the
+    same selections bit for bit (the skipped non-candidates are no-ops of
+    the full scan), 4·N·width reward probes instead of 4·N². A row with
+    more candidates than ``width`` would lose the surplus, so pass it
+    only where the masks are known to respect it. The naive oracle always
+    scans all N."""
     N = flat_w.shape[0]
+    if width is not None and impl == "ggc":
+        # counts its own 4·N·width probes
+        nbr = all_clients_graph_sparse(
+            key, flat_w, p, neighbors_from_adjacency(cand_masks, width),
+            reward_fn, budget, mix_impl=mix_impl, mesh=mesh,
+            client_axes=client_axes)
+        return adjacency_from_neighbors(nbr, N)
     _count_probes(N, N)
     if impl == "naive":
         ggc = make_ggc_naive(reward_fn, budget)

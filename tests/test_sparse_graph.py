@@ -14,6 +14,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import (CompressionConfig, DPFLConfig, ParticipationConfig,
                         run_dpfl, run_dpfl_reference)
+from repro.core import dpfl as dpfl_mod
 from repro.core.graph import (adjacency_from_neighbors, all_clients_bggc,
                               all_clients_bggc_sparse, all_clients_graph,
                               all_clients_graph_sparse,
@@ -114,13 +115,17 @@ def test_sparse_graph_mix_matches_oracle(impl, shape):
 # --------------------------------------------------- greedy decisions
 
 
-@pytest.fixture(scope="module")
-def small_setting():
+def _small_engine():
     data = make_federated_classification(
         seed=5, n_clients=6, n_clusters=2, partition="pathological",
         classes_per_client=3, feature_dim=8, n_train=16, n_val=16,
         n_test=16, noise=2.0, assign_level="cluster")
     return FLEngine(MLP(8, 16, 10), data, lr=0.05, batch_size=8)
+
+
+@pytest.fixture(scope="module")
+def small_setting():
+    return _small_engine()
 
 
 def _trained_flat(eng, epochs=2):
@@ -292,3 +297,106 @@ def test_sparse_budget_at_least_n(small_setting):
                                      budget=7, seed=0))
     assert new.comm_preprocess == dense.comm_preprocess
     np.testing.assert_array_equal(new.omega, dense.omega)
+
+
+# ------------------------------------------- dense refresh scanning lists
+
+
+@pytest.mark.parametrize("case", ["full", "participation", "naive"])
+def test_dense_list_scan_bitwise_matches_full_scan(small_setting, case):
+    """`all_clients_graph(..., width=w)` on BGGC-built masks (at most w
+    peers a row) selects bitwise what the full N-candidate scan selects,
+    with full candidacy and under a participation restriction, and
+    counts 4·N·w probes against 4·N². The naive oracle ignores the bound
+    and keeps its full scan."""
+    eng = small_setting
+    N, budget = 6, 3
+    width = dpfl_mod._nbr_width(N, budget)
+    flat = _trained_flat(eng)
+    reward = eng.make_reward_fn()
+    omega = eng.jit(lambda f: all_clients_bggc(
+        jax.random.PRNGKey(11), f, eng.p, jnp.ones((N, N), bool), reward,
+        budget))(flat)
+    assert int(jnp.max(omega.sum(1))) - 1 <= width
+    cand = omega
+    if case == "participation":
+        cand = omega & jnp.asarray([1, 0, 1, 1, 0, 1], bool)[None, :]
+    impl = "naive" if case == "naive" else "ggc"
+
+    def build(w):
+        return eng.jit(lambda f, c: all_clients_graph(
+            jax.random.PRNGKey(3), f, eng.p, c, reward, budget,
+            impl=impl, width=w))
+
+    full, lists = build(None), build(width)
+    want = np.asarray(full(flat, cand))
+    got = np.asarray(lists(flat, cand))
+    np.testing.assert_array_equal(got, want)
+    assert full.counts["ggc.probes"] == 4 * N * N
+    assert lists.counts["ggc.probes"] == (4 * N * N if impl == "naive"
+                                          else 4 * N * width)
+
+
+def _full_scan_refresh(monkeypatch):
+    """Make the dense aggregate and `_cached_refresh` run the full
+    N-candidate scan, as they did before they passed a row bound."""
+    full = dpfl_mod.all_clients_graph
+
+    def full_scan(*args, width=None, **kw):
+        return full(*args, **kw)
+
+    monkeypatch.setattr(dpfl_mod, "all_clients_graph", full_scan)
+
+
+_DENSE_CASES = {
+    "full": {},
+    "participation": {"participation": ParticipationConfig(
+        rate=0.5, model="bernoulli")},
+    "topk": {"compression": CompressionConfig(codec="topk",
+                                              topk_frac=0.25)},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DENSE_CASES))
+def test_dense_aggregate_list_scan_matches_full_scan(monkeypatch, case):
+    """Through the compiled dense round: the refresh scanning each Omega_k
+    as a list selects, round for round, what the full scan selects — with
+    full participation, under a participation schedule, and probing a
+    top-k codec's decoded table — and charges the same downloads."""
+    cfg = DPFLConfig(rounds=3, tau_init=2, tau_train=1, budget=3, seed=0,
+                     **_DENSE_CASES[case])
+    lists = run_dpfl(_small_engine(), cfg)
+    with monkeypatch.context() as m:
+        _full_scan_refresh(m)
+        full = run_dpfl(_small_engine(), cfg)
+    N, width = 6, dpfl_mod._nbr_width(6, 3)
+    assert full.ggc_probes == [4 * N * N] * 3
+    assert lists.ggc_probes == [4 * N * width] * 3
+    assert lists.comm_downloads == full.comm_downloads
+    assert lists.comm_bytes == full.comm_bytes
+    for a, b in zip(lists.graph_history, full.graph_history):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_allclose(lists.test_acc, full.test_acc, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", ["full", "participation"])
+def test_dense_engine_matches_reference_with_list_scan(small_setting, case):
+    """The compiled dense round and the host reference loop
+    (`_cached_refresh`) both scan lists: identical graphs, probe counts
+    and comm counts, and a refresh round charges sum(Omega) - N."""
+    eng = small_setting
+    cfg = DPFLConfig(rounds=3, tau_init=2, tau_train=1, budget=3, seed=0,
+                     **_DENSE_CASES[case])
+    new = run_dpfl(eng, cfg)
+    ref_ = run_dpfl_reference(eng, cfg)
+    N = 6
+    assert new.ggc_probes == ref_.ggc_probes == \
+        [4 * N * dpfl_mod._nbr_width(N, 3)] * 3
+    assert new.comm_downloads == ref_.comm_downloads
+    assert new.comm_bytes == ref_.comm_bytes
+    if case == "full":
+        assert new.comm_downloads == [int(new.omega.sum()) - N] * 3
+    assert len(new.graph_history) == len(ref_.graph_history) == 3
+    for a, b in zip(new.graph_history, ref_.graph_history):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_allclose(new.test_acc, ref_.test_acc, atol=1e-6)

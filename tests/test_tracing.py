@@ -13,8 +13,8 @@ from repro.configs.paper_cnn import CNNConfig
 from repro.core import DPFLConfig, run_dpfl, run_dpfl_reference
 from repro.core.dpfl import (_cached_bggc, _cached_refresh,
                              abstract_round_state, dpfl_round_step)
-from repro.core.graph import (all_clients_bggc, all_clients_graph,
-                              all_clients_graph_sparse,
+from repro.core.graph import (adjacency_from_neighbors, all_clients_bggc,
+                              all_clients_graph, all_clients_graph_sparse,
                               neighbors_from_adjacency)
 from repro.data import make_federated_classification
 from repro.fl.engine import FLEngine
@@ -136,8 +136,9 @@ def _counted(reward_fn, calls):
     return reward
 
 
-@pytest.mark.parametrize("builder", ["dense", "sparse", "bggc"])
-def test_probe_counter_matches_executed_rewards(mlp_engine, builder):
+@pytest.mark.parametrize("kind", ["dense", "sparse", "bggc",
+                                     "dense-list"])
+def test_probe_counter_matches_executed_rewards(mlp_engine, kind):
     eng = mlp_engine
     n, budget = eng.data.n_clients, 2
     key = jax.random.PRNGKey(7)
@@ -146,16 +147,25 @@ def test_probe_counter_matches_executed_rewards(mlp_engine, builder):
         | jnp.eye(n, dtype=bool)
     calls = []
     reward = _counted(eng.make_reward_fn(), calls)
-    if builder == "dense":
+    if kind == "dense":
         def refresh(key, flat, p, cand):
             return all_clients_graph(key, flat, p, cand, reward, budget)
         args, scan = (omega,), n
-    elif builder == "sparse":
+    elif kind == "sparse":
         width = 3
         def refresh(key, flat, p, cand):
             return all_clients_graph_sparse(key, flat, p, cand, reward,
                                             budget)
         args, scan = (neighbors_from_adjacency(omega, width),), width
+    elif kind == "dense-list":
+        width = 3
+        def refresh(key, flat, p, cand):
+            return all_clients_graph(key, flat, p, cand, reward, budget,
+                                     width=width)
+        # masks with at most ``width`` peers a row, as the bound promises
+        bounded = adjacency_from_neighbors(
+            neighbors_from_adjacency(omega, width), n)
+        args, scan = (bounded,), width
     else:
         def refresh(key, flat, p, cand):
             return all_clients_bggc(key, flat, p, cand, reward, budget)
@@ -174,7 +184,8 @@ def test_result_reports_executed_probes_per_round(mlp_engine, graph_repr):
     cfg = _cfg(graph_repr, budget=budget, refresh_period=2)
     res = run_dpfl(eng, cfg)
     ref = run_dpfl_reference(eng, cfg)
-    per_refresh = 4 * n * (n if graph_repr == "dense" else budget)
+    # both representations scan each Omega_k as a list of budget slots
+    per_refresh = 4 * n * budget
     assert res.ggc_probes == [per_refresh, 0, per_refresh, 0]
     assert ref.ggc_probes == res.ggc_probes
     random = run_dpfl(eng, _cfg(graph_repr, budget=budget,
